@@ -56,7 +56,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import configs, obs, optim
@@ -123,17 +122,17 @@ def _reduction_fn(scheme: str, mesh, grads):
     """Jitted shard_map of one reduction call; returns (fn, args)."""
     rep = jax.tree.map(lambda _: P(), grads)
     if scheme == "uncompressed":
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda g: C.uncompressed_psum_mean(g, "pod"),
-            mesh=mesh, in_specs=(rep,), out_specs=rep, check_rep=False,
+            mesh=mesh, in_specs=(rep,), out_specs=rep, check_vma=False,
         ))
         return fn, (grads,)
     if scheme == "gather":
         err = jax.tree.map(jnp.zeros_like, grads)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda g, e: C.compressed_psum_mean(g, e, "pod"),
             mesh=mesh, in_specs=(rep, rep), out_specs=(rep, rep),
-            check_rep=False,
+            check_vma=False,
         ))
         return fn, (grads, err)
     if scheme == "two_stage":
@@ -142,10 +141,10 @@ def _reduction_fn(scheme: str, mesh, grads):
         err2 = jax.tree.map(
             lambda g: jnp.zeros(C.two_stage_shard_len(g.size, n)), grads
         )
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda g, a, b: C.two_stage_psum_mean(g, a, b, "pod"),
             mesh=mesh, in_specs=(rep, rep, rep),
-            out_specs=(rep, rep, rep), check_rep=False,
+            out_specs=(rep, rep, rep), check_vma=False,
         ))
         return fn, (grads, err1, err2)
     raise ValueError(scheme)

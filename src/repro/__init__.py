@@ -1,11 +1,2 @@
 """repro — mixed-bit-width sparse CNN accelerator reproduction grown
-into a jax LM training/serving substrate.
-
-Importing the package installs small jax forward-compat shims (see
-`repro._compat`) so every entry point — tests, launchers, benchmarks —
-sees the same mesh API regardless of the installed jax version.
-"""
-
-from repro import _compat as _compat
-
-_compat.install()
+into a jax LM training/serving substrate."""

@@ -33,9 +33,9 @@ def unpack_tile(packed: jax.Array, bits: int) -> jax.Array:
     vpb = 8 // bits
     mask = (1 << bits) - 1
     kp, n = packed.shape
-    shifts = (jnp.arange(vpb, dtype=jnp.uint32) * bits).reshape(1, vpb, 1)
-    u = (packed.astype(jnp.uint32)[:, None, :] >> shifts) & mask
-    u = u.reshape(kp * vpb, n).astype(jnp.int32)
+    shifts = (jnp.arange(vpb, dtype=jnp.int32) * bits).reshape(1, vpb, 1)
+    u = (packed.astype(jnp.int32)[:, None, :] >> shifts) & mask
+    u = u.reshape(kp * vpb, n)
     if bits == 1:
         return jnp.where(u > 0, 1, -1)
     sign_bit = 1 << (bits - 1)

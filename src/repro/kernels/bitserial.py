@@ -36,8 +36,10 @@ def _kernel(x_ref, p_ref, scale_ref, o_ref, *, bits: int, nk: int):
     vpb = 8 // bits
     kp, bn = packed.shape
     mask = (1 << bits) - 1
-    shifts = (jnp.arange(vpb, dtype=jnp.uint32) * bits).reshape(1, vpb, 1)
-    u = (packed.astype(jnp.uint32)[:, None, :] >> shifts) & mask
+    # int32, not uint32: Mosaic has no uint32 -> float32 cast, and the
+    # masked words are < 2**bits, so the signed view is exact
+    shifts = (jnp.arange(vpb, dtype=jnp.int32) * bits).reshape(1, vpb, 1)
+    u = (packed.astype(jnp.int32)[:, None, :] >> shifts) & mask
     u = u.reshape(kp * vpb, bn)  # unsigned two's-complement words (bk, bn)
 
     if bits == 1:

@@ -61,9 +61,14 @@ def nm_spmm(
     vp = pad_to(values, 1, bn)
     sp = pad_to(select, 1, bn)
     scp = pad_to(sc, 1, bn)
+    groups = k // group_size
     gpb = block_groups
-    while (k // group_size) % gpb:
+    while groups % gpb:
         gpb //= 2
+    if (gpb * group_size) % 128:
+        # Mosaic needs the x block's K to be a multiple of 128 lanes or
+        # the whole K; the small VA layers (K <= 192) take the whole K
+        gpb = groups
     y = _nm_spmm.nm_spmm_2d(
         xp, vp, sp, scp,
         group_size=group_size, keep=keep,

@@ -2,9 +2,15 @@
 
 The chip streams the ifmap through the shared SPad and never materializes
 im2col patches in memory; this kernel does the same on TPU: the input tile
-lives once in VMEM, windows are cut *inside* the kernel (static strided
-slices), and the compressed weights are decompressed in VMEM and fed to
-the MXU. HBM traffic: the raw signal + the compressed weight stream only.
+lives once in VMEM, windows are cut *inside* the kernel (static
+unit-stride slices), and the compressed weights are decompressed in VMEM
+and fed to the MXU. HBM traffic: the raw signal + the compressed weight
+stream only.
+
+A stride s > 1 is taken out of the kernel: the host splits the padded row
+into its s phases (``x[p::s]``), and tap i of output t reads phase
+``i % s`` at ``t + i // s``. Every in-VMEM slice then has unit stride,
+which Mosaic lowers (a strided sublane slice it refuses).
 
 Shapes are the VA detector's (T<=512, C<=96, N<=96), so a whole (1, T, C)
 row plus all weights fit in VMEM trivially; the grid walks
@@ -23,7 +29,7 @@ from repro.kernels._common import decompress_tile
 
 
 def _kernel(
-    x_ref,  # (1, T_pad, C) float — full padded row in VMEM
+    x_ref,  # (1, stride, T_ph, C) float — the row's stride phases in VMEM
     v_ref,  # (Kk, bn)
     s_ref,  # (Kk, bn)
     scale_ref,  # (1, bn)
@@ -37,12 +43,16 @@ def _kernel(
     k_dense: int,
 ):
     bt = block_t
-    t0 = pl.program_id(1) * bt * stride  # input start of this output tile
-    span = (bt - 1) * stride + ksize
-    win = x_ref[0, pl.ds(t0, span), :].astype(jnp.float32)  # (span, C)
+    t0 = pl.program_id(1) * bt  # phase-row start of this output tile
+    span = bt + (ksize - 1) // stride
+    wins = [
+        x_ref[0, p, pl.ds(t0, span), :].astype(jnp.float32)  # (span, C)
+        for p in range(min(stride, ksize))
+    ]
     # im2col inside VMEM: row-order (tap, channel) == compiler's flatten.
     cols = [
-        win[i : i + (bt - 1) * stride + 1 : stride, :] for i in range(ksize)
+        wins[i % stride][i // stride : i // stride + bt, :]
+        for i in range(ksize)
     ]
     patches = jnp.concatenate(cols, axis=-1)  # (bt, ksize*C)
     if patches.shape[-1] < k_dense:  # group padding (zeros, like the chip)
@@ -85,9 +95,13 @@ def sparse_conv1d_call(
     pad_l = pad_total // 2
     bt = min(block_t, t_out)
     nt = pl.cdiv(t_out, bt)
-    # pad T so every tile's input span is in-bounds
-    span_end = (nt * bt - 1) * stride + ksize
-    xp = jnp.pad(x, ((0, 0), (pad_l, max(span_end - t - pad_l, 0)), (0, 0)))
+    # pad T so every tile's input span is in-bounds, to whole phases
+    t_ph = nt * bt + (ksize - 1) // stride
+    xp = jnp.pad(
+        x, ((0, 0), (pad_l, max(t_ph * stride - t - pad_l, 0)), (0, 0))
+    )[:, : t_ph * stride]
+    # (B, T_ph*stride, C) -> (B, stride, T_ph, C): phase p is xp[p::stride]
+    xp = xp.reshape(b, t_ph, stride, c).transpose(0, 2, 1, 3)
     bn = min(block_n, n)
     grid = (b, nt, pl.cdiv(n, bn))
     out = pl.pallas_call(
@@ -102,7 +116,9 @@ def sparse_conv1d_call(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, xp.shape[1], c), lambda bi, ti, ni: (bi, 0, 0)),
+            pl.BlockSpec(
+                (1, stride, t_ph, c), lambda bi, ti, ni: (bi, 0, 0, 0)
+            ),
             pl.BlockSpec((kk, bn), lambda bi, ti, ni: (0, ni)),
             pl.BlockSpec((kk, bn), lambda bi, ti, ni: (0, ni)),
             pl.BlockSpec((1, bn), lambda bi, ti, ni: (0, ni)),

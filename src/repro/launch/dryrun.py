@@ -199,10 +199,9 @@ def analyze(compiled, cfg: ArchConfig, cell: ShapeCell, mesh) -> dict:
     from repro.launch.hlo_count import weighted_cost
 
     n_dev = mesh.size
-    from repro._compat import cost_analysis_dict
 
     ma = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     wc = weighted_cost(compiled.as_text())  # loop-aware (hlo_count.py)
     mf = model_flops(cfg, cell)
     terms = H.roofline_terms(

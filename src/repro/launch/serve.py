@@ -28,11 +28,13 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro import configs, obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models import api
 from repro.serve import engine as E
@@ -40,13 +42,15 @@ from repro.serve import sharded as SH
 
 
 def serve_lm(args) -> None:
-    cfg = configs.reduced(args.arch) if args.reduced else configs.get(
-        args.arch
-    )
+    cfg = _lm_config(args)
     max_seq = args.prompt_len + args.max_new + 1
     model = api.build_model(cfg, tp=1, max_seq=max_seq)
     key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
+    mesh = make_serving_mesh(args.mesh) if args.mesh else None
+    params = (
+        SH.init_placed_params(model, key, mesh) if mesh is not None
+        else model.init(key)
+    )
     if args.quant_bits:
         params = E.quantize_for_serving(params, args.quant_bits)
         print(f"[serve] weights quantized to {args.quant_bits} bits")
@@ -65,8 +69,7 @@ def serve_lm(args) -> None:
     if args.temperature is not None:
         print(f"[serve] sampling: temperature={args.temperature} "
               f"top_k={args.top_k or 'off'} (per-request folded keys)")
-    if args.mesh:
-        mesh = make_serving_mesh(args.mesh)
+    if mesh is not None:
         plan = SH.plan_decode(model, params, mesh, batch_size=args.batch)
         print(
             f"[serve] mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}: "
@@ -108,7 +111,7 @@ def serve_load_sweep(args) -> None:
 
     from repro.obs import loadlab
 
-    _, make_engine, make_prompts = _build_lm_engine(args)
+    make_engine, make_prompts = _build_lm_engine(args)
     key = jax.random.PRNGKey(args.seed)
     cap = loadlab.run_serve_point(
         make_engine,
@@ -165,24 +168,37 @@ def _hostport(s: str) -> tuple[str, int]:
     return host or "127.0.0.1", int(port)
 
 
-def _build_lm_engine(args):
-    cfg = configs.reduced(args.arch) if args.reduced else configs.get(
-        args.arch
-    )
-    max_seq = args.prompt_len + args.max_new + 2
-    page = getattr(args, "page_size", None)
-    if page:
+def build_lm_engine(
+    cfg,
+    *,
+    batch: int,
+    prompt_len: int,
+    max_new: int,
+    seed: int = 0,
+    max_seq: Optional[int] = None,
+    page_size: Optional[int] = None,
+    pages_per_device: Optional[int] = None,
+    chunk_tokens: Optional[int] = None,
+    mesh_spec: Optional[str] = None,
+):
+    """Build what the LM serving entry points share for config `cfg`:
+    `(model, params, make_engine, make_prompts)`. `max_seq` defaults to
+    prompt + max_new + 2; with `page_size` it is rounded up to a whole
+    number of pages. With a `mesh_spec` ('D' or 'DxM') the params are
+    created directly in their mesh placement, never whole on one
+    device, and `make_engine` builds a `ShardedEngine` over them."""
+    if max_seq is None:
+        max_seq = prompt_len + max_new + 2
+    if page_size:
         # paged pools need page_size | every attention capacity; round
         # the derived max_seq up instead of bouncing the run
-        max_seq += (-max_seq) % page
+        max_seq += (-max_seq) % page_size
     model = api.build_model(cfg, tp=1, max_seq=max_seq)
-    key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
-    mesh = make_serving_mesh(args.mesh) if args.mesh else None
+    key = jax.random.PRNGKey(seed)
+    mesh = make_serving_mesh(mesh_spec) if mesh_spec else None
 
     paging = None
-    chunk_tokens = getattr(args, "chunk_tokens", None)
-    if page:
+    if page_size:
         from repro.dist import sharding as shd
         from repro.serve.paging import PagingConfig, validate_page_size
 
@@ -190,33 +206,58 @@ def _build_lm_engine(args):
             shd._axis_size(shd.data_axes(cfg, mesh), mesh)
             if mesh is not None else 1
         )
-        per_dev = getattr(args, "pages_per_device", None)
+        per_dev = pages_per_device
         if per_dev is None:
             # default: the dense pool's worth of pages (+1 scratch) —
             # paged then never rejects what dense would have seated
-            span = validate_page_size(page, model.attn_capacities())
-            per_dev = (args.batch // max(n_data, 1)) * span + 1
-        paging = PagingConfig(page, per_dev * max(n_data, 1))
+            span = validate_page_size(page_size, model.attn_capacities())
+            per_dev = (batch // max(n_data, 1)) * span + 1
+        paging = PagingConfig(page_size, per_dev * max(n_data, 1))
+
+    params = (
+        SH.init_placed_params(model, key, mesh) if mesh is not None
+        else model.init(key)
+    )
 
     def make_engine():
         if mesh is not None:
             return SH.ShardedEngine(
-                model, params, batch_size=args.batch, mesh=mesh,
+                model, params, batch_size=batch, mesh=mesh,
                 paging=paging, chunk_tokens=chunk_tokens,
             )
         return E.Engine(
-            model, params, batch_size=args.batch,
+            model, params, batch_size=batch,
             paging=paging, chunk_tokens=chunk_tokens,
         )
 
     def make_prompts(n):
         toks = jax.random.randint(
-            jax.random.fold_in(key, 2), (n, args.prompt_len), 0,
-            cfg.vocab,
+            jax.random.fold_in(key, 2), (n, prompt_len), 0, cfg.vocab,
         )
         return [jnp.asarray(toks[i], jnp.int32) for i in range(n)]
 
-    return cfg, make_engine, make_prompts
+    return model, params, make_engine, make_prompts
+
+
+def _lm_config(args):
+    return configs.reduced(args.arch) if args.reduced else configs.get(
+        args.arch
+    )
+
+
+def _build_lm_engine(args):
+    _, _, make_engine, make_prompts = build_lm_engine(
+        _lm_config(args),
+        batch=args.batch,
+        prompt_len=args.prompt_len,
+        max_new=args.max_new,
+        seed=args.seed,
+        page_size=args.page_size,
+        pages_per_device=args.pages_per_device,
+        chunk_tokens=args.chunk_tokens,
+        mesh_spec=args.mesh,
+    )
+    return make_engine, make_prompts
 
 
 def serve_listen(args) -> None:
@@ -227,7 +268,7 @@ def serve_listen(args) -> None:
 
     from repro.serve.frontend import Frontend, FrontendConfig
 
-    _, make_engine, _ = _build_lm_engine(args)
+    make_engine, _ = _build_lm_engine(args)
     fe = Frontend(
         engine=make_engine(),
         cfg=FrontendConfig(admission_rate_rps=args.admission_rate),
@@ -311,7 +352,7 @@ def serve_frontend_sweep(args) -> None:
     from repro.serve.frontend import Frontend
     from repro.stream.runner import FleetRunner
 
-    _, make_engine, make_prompts = _build_lm_engine(args)
+    make_engine, make_prompts = _build_lm_engine(args)
     rate = args.admission_rate
     if rate is None:
         rate = loadlab.run_serve_point(
@@ -462,6 +503,7 @@ def main() -> None:
                          "this into page-sized chunks interleaved "
                          "with decode ticks")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.pages_per_device and not args.page_size:
         ap.error("--pages-per-device requires --page-size")
     if args.top_k and args.temperature is None:

@@ -25,6 +25,7 @@ import jax
 
 from repro import obs
 from repro.core import compiler, vadetect
+from repro.launch.compile_cache import enable_compile_cache
 from repro.stream import FleetConfig, simulate
 
 
@@ -243,6 +244,7 @@ def main() -> None:
                          "(event log) and PREFIX.json (Chrome/Perfetto "
                          "trace)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace_out:
         # before the runner compiles so its jit cell registers with the
         # probe

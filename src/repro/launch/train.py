@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from repro import configs, obs
 from repro.data import iegm, lm
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_multipod_mesh, make_smoke_mesh
 from repro.models import api
 from repro.optim import adamw, linear_warmup_cosine
@@ -232,6 +233,7 @@ def main() -> None:
              "and PREFIX.json (Chrome/Perfetto trace)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if args.trace_out:
         # before any step compilation so jit cells register with the probe
         obs.configure(enabled=True)

@@ -31,8 +31,6 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from repro._compat import cost_analysis_dict
-
 
 def jit_cache_size(fn) -> Optional[int]:
     """Compiled-variant count of a jitted callable (None if the
@@ -231,8 +229,8 @@ def observe_memory(registry) -> int:
 def cost_gauges(registry, name: str, compiled) -> dict:
     """Fold a compiled cell's `cost_analysis` flops/bytes estimates
     into gauges (`<name>.flops`, `<name>.bytes_accessed`); returns the
-    normalized cost dict."""
-    ca = cost_analysis_dict(compiled)
+    cost dict."""
+    ca = compiled.cost_analysis()
     if "flops" in ca:
         registry.gauge(f"{name}.flops").set(float(ca["flops"]))
     if "bytes accessed" in ca:

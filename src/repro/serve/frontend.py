@@ -28,7 +28,7 @@ UTF-8 JSON object:
      "tokens": [int...]}
     {"type": "lm_result", "uid": int, "status": "rejected",
      "reason": "admission_rate"|"queue_full"|"invalid"
-               |"pages_exhausted",
+               |"pages_exhausted"|"engine_failed",
      "detail": str}
     {"type": "segment_ack", "patient": int, "seq": int,
      "status": "enqueued"|"deferred", "urgent": bool}
@@ -52,7 +52,11 @@ a silent drop:
     (`lm_queue_limit`). Exceeding either sheds the request with a
     typed `rejected` reply (reason `admission_rate` / `queue_full`);
     engine-level validation failures (empty prompt, max_new <= 0,
-    duplicate uid) come back as reason `invalid`. Every accepted
+    duplicate uid, token ids outside the vocabulary, a uid outside
+    [0, 2**32)) come back as
+    reason `invalid`. Any other engine failure ends the driver thread:
+    every pending request is rejected with reason `engine_failed` and
+    `stop()` raises the failure. Every accepted
     request terminates in exactly one `completed` XOR `rejected`
     reply: submitted == completed + rejected, always.
   * stream ROUTINE segments pass their own bucket
@@ -96,6 +100,16 @@ REASON_ADMISSION = "admission_rate"
 REASON_QUEUE_FULL = "queue_full"
 REASON_INVALID = "invalid"
 REASON_PAGES = "pages_exhausted"
+REASON_ENGINE = "engine_failed"
+
+# client ids that key PRNG streams (LM uid, segment seq) go through
+# jax.random.fold_in, whose data is a uint32
+_KEY_DATA_LIMIT = 2**32
+
+
+def _engine_failed(err: BaseException) -> dict:
+    return {"status": STATUS_REJECTED, "reason": REASON_ENGINE,
+            "detail": f"serving engine failed: {err!r}"}
 
 
 def encode_frame(msg: dict, *, max_frame_bytes: int = 1 << 20) -> bytes:
@@ -455,6 +469,28 @@ class Frontend:
                 "detail": "this frontend serves no LM engine",
             })
             return
+        vocab = self.engine.model.cfg.vocab
+        bad = [t for t in prompt if not 0 <= t < vocab]
+        if eos is not None and not 0 <= eos < vocab:
+            bad.append(eos)
+        detail = (f"token ids {bad[:4]} outside [0, {vocab})" if bad
+                  else None)
+        if not 0 <= uid < _KEY_DATA_LIMIT:
+            # a sampling engine folds the uid into its PRNG key (uint32)
+            detail = f"uid {uid} outside [0, 2**32)"
+        if detail is not None:
+            # JSON integers are unbounded: an id out of range must stop
+            # here, not fail inside the driver
+            self._finish_lm(uid, rid, reply, {
+                "status": STATUS_REJECTED, "reason": REASON_INVALID,
+                "detail": detail,
+            })
+            return
+        if self._driver_err is not None:
+            self._finish_lm(uid, rid, reply, _engine_failed(
+                self._driver_err
+            ))
+            return
         if uid in self._pending_lm:
             self._finish_lm(uid, rid, reply, {
                 "status": STATUS_REJECTED, "reason": REASON_INVALID,
@@ -504,6 +540,9 @@ class Frontend:
                     f"patient {patient} outside fleet of "
                     f"{self.n_patients}"
                 )
+            if not 0 <= seq < _KEY_DATA_LIMIT:
+                # segment content is keyed by fold_in(seq), a uint32
+                raise ValueError(f"seq {seq} outside [0, 2**32)")
         except (KeyError, TypeError, ValueError) as e:
             reply({"type": "segment_ack",
                    "patient": msg.get("patient"),
@@ -597,11 +636,22 @@ class Frontend:
     def _now(self) -> float:
         return self._clock() - self._epoch
 
+    def _fail_pending_lm(self) -> None:
+        # event-loop thread (posted by a dying driver): no pending LM
+        # request can finish, so each gets its terminal reply now
+        payload = _engine_failed(self._driver_err)
+        for uid in list(self._pending_lm):
+            self._resolve_lm(uid, payload)
+
     def _drive(self) -> None:
         try:
             self._drive_inner()
         except BaseException as e:  # surfaced by stop()
             self._driver_err = e
+            try:
+                self._post(self._fail_pending_lm)
+            except RuntimeError:
+                pass  # event loop already closed: nobody is waiting
 
     def _drive_inner(self) -> None:
         import jax.numpy as jnp
@@ -642,10 +692,12 @@ class Frontend:
                             "reason": REASON_PAGES,
                             "detail": str(e),
                         })
-                    except Exception as e:
+                    except (ValueError, TypeError) as e:
                         # engine-boundary validation (empty prompt,
                         # max_new <= 0, duplicate in-flight uid) comes
-                        # back as an explicit typed rejection
+                        # back as an explicit typed rejection; any other
+                        # failure (a device error) ends `_drive`: pending
+                        # requests get `engine_failed` and stop() raises
                         self._post(self._resolve_lm, uid, {
                             "status": STATUS_REJECTED,
                             "reason": REASON_INVALID,
@@ -889,6 +941,7 @@ __all__ = [
     "encode_frame",
     "read_frame",
     "REASON_ADMISSION",
+    "REASON_ENGINE",
     "REASON_INVALID",
     "REASON_PAGES",
     "REASON_QUEUE_FULL",

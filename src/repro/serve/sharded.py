@@ -229,6 +229,15 @@ def place_params(params: Any, plan: DecodePlan) -> Any:
     return jax.device_put(params, plan.params)
 
 
+def init_placed_params(model: Model, key: jax.Array, mesh: Mesh) -> Any:
+    """`model.init(key)` created directly in `plan_decode`'s parameter
+    placement, so no device ever holds the whole tree (a published-width
+    model does not fit one device)."""
+    avals = jax.eval_shape(model.init, key)
+    shardings = shd.named(shd.param_specs(avals, model.cfg, mesh), mesh)
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
 def sharded_generate(
     model: Model,
     params: Any,

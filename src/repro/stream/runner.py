@@ -112,7 +112,6 @@ class FleetRunner:
         self.cfg = cfg
         self.path = path
         self.mesh = mesh
-        self._shapes_seen: set[int] = set()
         if path == "twin":
             weights = twin_weights(program)
             meta = program.layer_meta
@@ -154,7 +153,6 @@ class FleetRunner:
                     f"{self.n_devices} mesh devices"
                 )
             signals = jax.device_put(signals, self._in_sharding)
-        self._shapes_seen.add(int(signals.shape[0]))
         return self._infer(signals)
 
     # -- accounting ---------------------------------------------------------
@@ -186,8 +184,4 @@ class FleetRunner:
         """Compiled-variant count of the classify function — equals the
         number of distinct batch shapes ever seen. The scheduler's
         pad-to-bucket contract keeps this at len(buckets)."""
-        try:
-            n = self._infer._cache_size()  # jax >= 0.4.x
-        except AttributeError:
-            n = len(self._shapes_seen)
-        return int(n)
+        return int(self._infer._cache_size())

@@ -258,8 +258,6 @@ def make_dp_step_compressed(
     the launcher's composed in-pod-sharded variant see
     `make_multipod_train_step`.
     """
-    from jax.experimental.shard_map import shard_map
-
     if compress and scheme not in _SCHEMES:
         raise ValueError(f"scheme {scheme!r}: expected one of {_SCHEMES}")
 
@@ -292,12 +290,12 @@ def make_dp_step_compressed(
     rep = P()  # replicated across the dp axis
     dp = P(axis)
     state_spec = {"params": rep, "opt": rep, "step": rep, "err": dp}
-    return shard_map(
+    return jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(state_spec, dp),
         out_specs=(state_spec, rep),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -352,8 +350,6 @@ def make_multipod_train_step(
     divide by the pod count — and is what `fault.run_training` drives;
     `state_shardings` feeds checkpoint-restore placement.
     """
-    from jax.experimental.shard_map import shard_map
-
     if compress and scheme not in _SCHEMES:
         raise ValueError(f"scheme {scheme!r}: expected one of {_SCHEMES}")
     if "pod" not in mesh.axis_names:
@@ -409,12 +405,12 @@ def make_multipod_train_step(
     step_b = obs.get().probe.track(
         "train.multipod.step_b",
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 reduce_body,
                 mesh=mesh,
                 in_specs=(g_spec, err_spec),
                 out_specs=(mean_spec, err_spec),
-                check_rep=False,
+                check_vma=False,
             ),
             in_shardings=(g_shard, err_shard),
             out_shardings=(shd.named(mean_spec, mesh), err_shard),

@@ -6,14 +6,6 @@ import sys
 # and benchmarks must see the host's real single device.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # container has no hypothesis: deterministic stub
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_stub
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
@@ -38,9 +30,8 @@ def pytest_terminal_summary(terminalreporter, exitstatus, config):
         for rep in reports:
             if getattr(rep, "when", None) != "call":
                 continue
-            # nodeid, not location[0]: wrapped tests (hypothesis stub)
-            # report their wrapper's code location, which would lump
-            # every property test under tests/_hypothesis_stub.py
+            # nodeid, not location[0]: a wrapped test reports its
+            # wrapper's code location
             entry = times.setdefault(
                 rep.nodeid.split("::")[0], [0.0, 0]
             )

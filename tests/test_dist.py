@@ -122,16 +122,14 @@ def test_compressed_sgd_converges():
 
 def test_compressed_psum_mean_single_device():
     """Under a 1-device shard_map the compressed mean == plain mean."""
-    from jax.experimental.shard_map import shard_map
-
     mesh = jax.make_mesh((1,), ("pod",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jnp.arange(8.0)}
     e = {"w": jnp.zeros(8)}
-    f = shard_map(
+    f = jax.shard_map(
         lambda gg, ee: C.compressed_psum_mean(gg, ee, "pod"),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     mean, new_e = f(g, e)
     np.testing.assert_allclose(mean["w"] + new_e["w"], g["w"], rtol=1e-4,
@@ -141,17 +139,15 @@ def test_compressed_psum_mean_single_device():
 def test_two_stage_single_device_telescopes():
     """n=1 degenerates to double quantization of the same leaf; the
     output plus both residuals still reconstructs the input exactly."""
-    from jax.experimental.shard_map import shard_map
-
     mesh = jax.make_mesh((1,), ("pod",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jax.random.normal(jax.random.PRNGKey(3), (37,))}  # odd size
     e1 = {"w": jnp.zeros(37)}
     e2 = {"w": jnp.zeros(C.two_stage_shard_len(37, 1))}
-    f = shard_map(
+    f = jax.shard_map(
         lambda a, b, c: C.two_stage_psum_mean(a, b, c, "pod"),
         mesh=mesh, in_specs=(P(), P(), P()), out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     mean, n1, n2 = f(g, e1, e2)
     np.testing.assert_allclose(
@@ -164,16 +160,14 @@ def test_uncompressed_finite_guard():
     """`compress=False` shares failure semantics with the compressed
     path by default: non-finite entries are zeroed, not propagated;
     `finite_guard=False` is the documented raw-IEEE opt-out."""
-    from jax.experimental.shard_map import shard_map
-
     mesh = jax.make_mesh((1,), ("pod",),
                          axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jnp.array([1.0, jnp.inf, -jnp.inf, jnp.nan, 2.0])}
 
     def run(**kw):
-        return shard_map(
+        return jax.shard_map(
             lambda gg: C.uncompressed_psum_mean(gg, "pod", **kw),
-            mesh=mesh, in_specs=(P(),), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
         )(g)
 
     guarded = run()

@@ -93,23 +93,21 @@ def test_compressed_psum_mean_matches_uncompressed():
     """int8+error-feedback mean across real devices stays within one
     quantization step of the f32 pmean, and mean + mean-of-residuals
     recovers it exactly (telescoping)."""
-    from jax.experimental.shard_map import shard_map
-
     n = jax.device_count()
     mesh = _mesh((n,), ("pod",))
     k = 256
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (n * k,))}
     e = {"w": jnp.zeros((n * k,))}
 
-    comp = shard_map(
+    comp = jax.shard_map(
         lambda gg, ee: C.compressed_psum_mean(gg, ee, "pod"),
         mesh=mesh, in_specs=(P("pod"), P("pod")),
-        out_specs=(P(), P("pod")), check_rep=False,
+        out_specs=(P(), P("pod")), check_vma=False,
     )
-    unc = shard_map(
+    unc = jax.shard_map(
         lambda gg: C.uncompressed_psum_mean(gg, "pod"),
         mesh=mesh, in_specs=(P("pod"),), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     mean_c, err = comp(g, e)
     mean_u = unc(g)

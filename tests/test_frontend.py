@@ -235,3 +235,93 @@ def test_socket_loopback_lineage(built):
     assert cp["hop_names"][0] == "frontend/ingress"
     assert cp["hop_names"][-1] == "frontend/reply"
     assert cp["total_s"] > 0
+
+
+# -- engine failures at submit ----------------------------------------------
+
+
+class _RaisingEngine:
+    """An engine whose `submit` raises `exc`: validation errors must
+    come back as typed rejections, anything else must end the engine
+    thread, reject what is pending as `engine_failed`, and surface from
+    `stop()` instead of reading as a client error."""
+
+    _queue = ()
+    model = type("M", (), {"cfg": configs.reduced("qwen3_8b")})
+
+    def __init__(self, exc: Exception):
+        self.exc = exc
+
+    def submit(self, req) -> None:
+        raise self.exc
+
+
+@pytest.mark.parametrize("exc,surfaces", [
+    (ValueError("request 0: empty prompt"), False),
+    (TypeError("prompt must be int32"), False),
+    (RuntimeError("device lost"), True),
+])
+def test_engine_submit_failure_routing(exc, surfaces):
+    async def go():
+        fe = Frontend(engine=_RaisingEngine(exc))
+        await fe.start(host=None)
+        client = InProcClient(fe)
+        fut = await client.send_lm(uid=0, prompt=[1, 2, 3], max_new=2)
+        res = await asyncio.wait_for(fut, 10.0)
+        if surfaces:
+            # a request arriving after the driver died is refused at once
+            late = await asyncio.wait_for(
+                await client.send_lm(uid=1, prompt=[1], max_new=2), 10.0
+            )
+            with pytest.raises(RuntimeError, match="driver thread died") as ei:
+                await fe.stop()
+            assert ei.value.__cause__ is exc
+            return res, late
+        await fe.stop()
+        return res, None
+
+    res, late = asyncio.run(go())
+    assert res["status"] == "rejected"
+    if surfaces:
+        for r in (res, late):
+            assert r["reason"] == "engine_failed"
+            assert "device lost" in r["detail"]
+    else:
+        assert res["reason"] == "invalid"
+        assert str(exc) in res["detail"]
+
+
+def test_out_of_vocab_tokens_rejected_at_ingress(built):
+    """Token ids outside [0, vocab), and uids or segment seqs outside
+    the uint32 PRNG-key domain — JSON integers are unbounded, so one can
+    exceed int32 — come back as `invalid` rejections, and the driver
+    keeps serving: the next well-formed request completes."""
+    make_engine, prompts = built
+    vocab = configs.reduced("qwen3_8b").vocab
+    good = prompts(1)[0]
+    bad = [(0, [2**40], None), (1, [-1], None), (2, [1, vocab], None),
+           (3, good, vocab), (2**40, good, None), (-1, good, None)]
+
+    async def go():
+        fe = Frontend(engine=make_engine(), n_patients=2)
+        fe.warm(PROMPT_LEN)
+        await fe.start(host=None)
+        client = InProcClient(fe)
+        futs = [await client.send_lm(uid=u, prompt=p, max_new=MAX_NEW,
+                                     eos=eos)
+                for u, p, eos in bad]
+        segs = [await client.send_segment(0, s) for s in (2**40, -1)]
+        futs.append(await client.send_lm(
+            uid=len(bad), prompt=good, max_new=MAX_NEW
+        ))
+        res = [await asyncio.wait_for(f, 60.0) for f in futs]
+        seg_res = [await asyncio.wait_for(f, 60.0) for f in segs]
+        await fe.stop()  # raises if the driver died
+        return res, seg_res
+
+    res, seg_res = asyncio.run(go())
+    for r in res[:-1] + seg_res:
+        assert r["status"] == "rejected" and r["reason"] == "invalid"
+        assert "outside" in r["detail"]
+    assert res[-1]["status"] == "completed"
+    assert len(res[-1]["tokens"]) == MAX_NEW

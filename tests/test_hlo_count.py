@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro._compat import cost_analysis_dict
 from repro.launch.hlo_count import weighted_cost
 
 
@@ -21,7 +20,7 @@ def test_plain_matmul_flops():
     )
     wc = weighted_cost(c.as_text())
     assert wc.flops == 2 * M * K * N
-    assert wc.flops == cost_analysis_dict(c)["flops"]  # loop-free: agree
+    assert wc.flops == c.cost_analysis()["flops"]  # loop-free: agree
 
 
 def test_scan_flops_multiplied_by_trip():

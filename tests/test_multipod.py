@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import configs, optim
@@ -62,11 +61,11 @@ def _two_stage_reduce(n, sizes):
         m, a, b = C.two_stage_psum_mean(sq(g), sq(e1), sq(e2), "pod")
         return m, ex(a), ex(b)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("pod"), P("pod"), P("pod")),
         out_specs=(P(), P("pod"), P("pod")),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -180,9 +179,9 @@ def test_nonfinite_injection_parity_across_paths():
             m, ne = C.compressed_psum_mean(sq(gg), sq(ee), "pod")
             return m, jax.tree.map(lambda x: x[None], ne)
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh, in_specs=(P("pod"), P("pod")),
-            out_specs=(P(), P("pod")), check_rep=False,
+            out_specs=(P(), P("pod")), check_vma=False,
         )
         return f(g, e)[0]["w"]
 
@@ -194,12 +193,12 @@ def test_nonfinite_injection_parity_across_paths():
         )[0]["l0"]
 
     def run_uncompressed(**kw):
-        f = shard_map(
+        f = jax.shard_map(
             lambda gg: C.uncompressed_psum_mean(
                 jax.tree.map(lambda x: x[0], gg), "pod", **kw
             ),
             mesh=mesh, in_specs=(P("pod"),), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         return f(g)["w"]
 
