@@ -1,0 +1,14 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - busy / window, averaged over the chips used)."""
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "va_segments_per_s"
+SOURCE = "device_trace"
+
+
+def read(r):
+    t = r.trace
+    if not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
