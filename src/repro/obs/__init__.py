@@ -13,11 +13,16 @@ subsystem shares:
 
 The hot paths (trainer step loop, stream fleet loop, serve engine
 admission/tick) call `obs.get()` each time and emit unconditionally;
-the **default telemetry is disabled** and every emission is a no-op
-costing nanoseconds (asserted in `tests/test_obs.py`), so the
-instrumentation has no off-switch to forget and no measurable tax when
-off. Launchers enable it behind `--trace-out`, benchmarks always
-enable it and attach `telemetry_section()` to their BENCH records.
+the **default telemetry is disabled**: registry and tracer emissions
+are no-ops costing nanoseconds, and a span is a bare
+`jax.profiler.TraceAnnotation` (about a microsecond; bounds asserted in
+`tests/test_obs.py`), so the instrumentation has no off-switch to
+forget and no measurable tax when off. The annotation is what puts the
+program's spans into a `jax.profiler` trace, on the device's clock,
+whether telemetry is on or off. Launchers enable telemetry behind
+`--trace-out`, benchmarks always enable it and attach
+`telemetry_section()` to their BENCH records. Hot-path call sites build
+span attributes only under `tel.enabled`: the annotation takes none.
 
 Usage:
 
@@ -32,6 +37,8 @@ Usage:
 """
 
 from __future__ import annotations
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.jaxprobe import (
     NULL_PROBE,
@@ -85,7 +92,13 @@ class Telemetry:
     # hot-path conveniences ---------------------------------------------
 
     def span(self, name: str, cat: str = "app", **attrs):
-        return self.tracer.span(name, cat, **attrs)
+        """A span of the JSONL tracer (which also annotates the
+        profiler's trace) when enabled; else the bare profiler
+        annotation, recorded only while a `jax.profiler` session runs.
+        Attributes go to the JSONL tracer alone."""
+        if self.enabled:
+            return self.tracer.span(name, cat, **attrs)
+        return TraceAnnotation(name)
 
     def block(self, x):
         """`jax.block_until_ready(x)` only when telemetry is enabled —

@@ -21,9 +21,14 @@ export then mirrors those spans onto a second process track named
 "virtual time" with the modeled timestamps, so one trace shows the wall
 timeline and the modeled fleet timeline side by side.
 
-A disabled tracer returns one shared no-op context manager from
-`span()` — the hot-path cost of an un-traced span is a dict miss and a
-`with` statement, nanoseconds per call.
+Profiler sink: every span also opens a `jax.profiler.TraceAnnotation`
+of the same name (no attributes: JAX would encode them into the event's
+name), so while a `jax.profiler` session runs the span lands on the
+host plane of the profiler's trace, on the device trace's clock. With
+telemetry disabled, `Telemetry.span` returns that bare annotation
+alone: the profiler records it only while a session runs, and with no
+session it costs about a microsecond. A disabled `Tracer` returns one
+shared no-op context manager from `span()`.
 
 CLI (the CI trace smoke): validate a JSONL event log and a Chrome
 export in one call —
@@ -38,6 +43,8 @@ import json
 import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 EVENT_TYPES = ("span", "instant", "counter")
 
@@ -67,7 +74,7 @@ NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("tracer", "name", "cat", "attrs", "_t0",
-                 "span_id", "parent_id")
+                 "span_id", "parent_id", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: dict):
@@ -87,11 +94,14 @@ class _Span:
         self.span_id = self.tracer._next_id()
         self.parent_id = stack[-1] if stack else ROOT_SPAN_ID
         stack.append(self.span_id)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._annotation.__exit__(None, None, None)
         stack = self.tracer._open_stack()
         # tolerate a mis-nested exit rather than corrupting the stack
         if stack and stack[-1] == self.span_id:

@@ -288,6 +288,10 @@ class Engine:
         # request here
         self.admission_rowsteps = 0
         self.admission_prefills = 0
+        # device-to-host reads the engine made (`_to_host`): a token per
+        # occupied slot per tick, a first token and a prompt's last
+        # token per admission
+        self.host_reads = 0
         # request latency tracking (wall): submit time per uid until the
         # first token, then last-token time per slot for inter-token
         # gaps — populated only when telemetry is enabled
@@ -443,7 +447,6 @@ class Engine:
                 prompt_len=int(req.prompt.shape[0]),
             )
         self._queue.append(req)
-        tel.registry.counter("serve.submitted_total").inc()
 
     def _worst_pages(self, prompt_len: int, max_new: int) -> int:
         """Worst-case pages a request can ever hold: prompt + max_new
@@ -582,7 +585,7 @@ class Engine:
             )
         src, dst = [], []
         for j, (slot, req) in enumerate(pairs):
-            first = int(firsts[j])
+            first = int(self._to_host(firsts[j]))
             req.output.append(first)
             if tel.enabled:
                 t_now = time.perf_counter()
@@ -648,7 +651,8 @@ class Engine:
         self.pos = self.pos.at[slot].set(s_len - 1)
         self.tokens = self.tokens.at[slot].set(first)
         self.active = self.active.at[slot].set(True)
-        self._ctok = self._ctok.at[slot].set(int(req.prompt[-1]))
+        self._ctok = self._ctok.at[slot].set(
+            int(self._to_host(req.prompt[-1])))
         self._cpos = self._cpos.at[slot].set(s_len - 1)
         self._slot_keys = self._slot_keys.at[slot].set(
             request_key(self.key, req.uid)
@@ -727,7 +731,7 @@ class Engine:
         c = self.chunk_tokens
         rows = self._admission_rows(1)
         step, _, place = self._chunk_cell(c, rows)
-        prompt = np.asarray(st.req.prompt, np.int32)
+        prompt = self._to_host(st.req.prompt).astype(np.int32)
         s = prompt.shape[0]
         lo = st.done
         hi = min(lo + c, s)
@@ -769,16 +773,16 @@ class Engine:
         slot = free[0]
         s_len = int(req.prompt.shape[0])
         if self.greedy:
-            first = int(jnp.argmax(st.logits[0]))
+            first = int(self._to_host(jnp.argmax(st.logits[0])))
         else:
-            first = int(sample_tokens(
+            first = int(self._to_host(sample_tokens(
                 st.logits[:1],
                 jax.vmap(jax.random.fold_in)(
                     request_key(self.key, req.uid)[None],
                     jnp.zeros((1,), jnp.int32),
                 ),
                 temperature=self.temperature, top_k=self.top_k,
-            )[0])
+            )[0]))
         req.output.append(first)
         if tel.enabled:
             t_now = time.perf_counter()
@@ -836,38 +840,34 @@ class Engine:
         )
         return logits
 
+    def _to_host(self, x) -> np.ndarray:
+        """`x` as a host array, counting a device-to-host read in
+        `host_reads` when it lives on a device."""
+        if isinstance(x, jax.Array):
+            self.host_reads += 1
+        return np.asarray(x)
+
     @driver_thread_only
     def tick(self) -> int:
-        """One decode tick for the whole pool; returns #active slots."""
+        """One decode tick for the whole pool; returns #active slots.
+        Its spans tile it: `serve/admission`, `serve/pages`,
+        `serve/decode`, `serve/emit`."""
         tel = obs.get()
         with tel.span("serve/tick", cat="serve"):
-            n_active = self._tick_inner(tel)
-        tel.registry.gauge("serve.active_slots").set(n_active)
-        return n_active
+            return self._tick_inner(tel)
 
     def _tick_inner(self, tel) -> int:
-        self._admit()
-        n_chunk = (
-            self._chunk_tick(tel) if self.chunk_tokens is not None else 0
-        )
+        with tel.span("serve/admission", cat="serve"):
+            self._admit()
+            n_chunk = (
+                self._chunk_tick(tel) if self.chunk_tokens is not None
+                else 0
+            )
         if not any(r is not None for r in self._slots):
             return n_chunk
         if self._pg is not None:
-            # page-boundary crossings: every occupied slot writes at
-            # position _hpos+1 this tick; map any newly needed logical
-            # page before the decode cell sees the table (the seated
-            # reservation guarantees alloc succeeds)
-            for slot, req in enumerate(self._slots):
-                if req is None:
-                    continue
-                nw = self._hpos[slot] + 1
-                need = pages_for_position(nw, self._page, self._span)
-                while self._npages[slot] < need:
-                    self._tbl[slot, self._npages[slot]] = self._pg.alloc(
-                        req.uid
-                    )
-                    self._npages[slot] += 1
-                self._hpos[slot] = nw
+            with tel.span("serve/pages", cat="serve"):
+                self._map_pages()
         # active slots advance with their pending token; inactive slots
         # re-feed their last-fed state (no junk writes into positions a
         # future tenant's scatter-seat wouldn't overwrite anyway)
@@ -904,11 +904,35 @@ class Engine:
         # produced one token this tick: one vectorized bump, not a
         # per-slot dispatch on the per-token hot loop
         self._nout = self._nout + self.active.astype(jnp.int32)
+        with tel.span("serve/emit", cat="serve"):
+            return self._emit(tel, nxt) + n_chunk
+
+    def _map_pages(self) -> None:
+        """Page-boundary crossings: every occupied slot writes at
+        position _hpos+1 this tick; map any newly needed logical page
+        before the decode cell sees the table (the seated reservation
+        guarantees alloc succeeds)."""
+        for slot, req in enumerate(self._slots):
+            if req is None:
+                continue
+            nw = self._hpos[slot] + 1
+            need = pages_for_position(nw, self._page, self._span)
+            while self._npages[slot] < need:
+                self._tbl[slot, self._npages[slot]] = self._pg.alloc(
+                    req.uid
+                )
+                self._npages[slot] += 1
+            self._hpos[slot] = nw
+
+    def _emit(self, tel, nxt: jax.Array) -> int:
+        """Each occupied slot's token from the decode (a host read
+        each); finished requests free their slot and pages. Returns the
+        slots still active."""
         n_active = 0
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
-            tok = int(nxt[slot])
+            tok = int(self._to_host(nxt[slot]))
             req.output.append(tok)
             if tel.enabled:
                 t_now = time.perf_counter()
@@ -918,7 +942,6 @@ class Engine:
                         "serve.inter_token_s"
                     ).observe(t_now - t_prev)
                 self._t_last_tok[slot] = t_now
-            tel.registry.counter("serve.tokens_total").inc()
             if (req.eos is not None and tok == req.eos) or len(
                 req.output
             ) >= req.max_new:
@@ -937,7 +960,6 @@ class Engine:
                         self._slot_shard(slot)
                     )
                     self._npages[slot] = 0
-                tel.registry.counter("serve.completed_total").inc()
                 if tel.enabled:
                     tel.tracer.instant(
                         "serve/finish", cat="serve",
@@ -946,7 +968,7 @@ class Engine:
                     )
             else:
                 n_active += 1
-        return n_active + n_chunk
+        return n_active
 
     def cache_bytes_in_use(self) -> int:
         """Logically resident cache bytes: occupied slots' dense

@@ -746,6 +746,7 @@ class Frontend:
             patient=patient, seq=seq, arrival_s=now,
             deadline_s=now + deadline_rel,
         ))
+        obs.get().registry.counter("stream.enqueued_total").inc()
 
     def _flush_stream(self) -> None:
         import jax.numpy as jnp

@@ -113,6 +113,36 @@ class _SignalBank:
         return self._signals[idx]
 
 
+def _admit(sched: MicroBatchScheduler, refs: list, i: int,
+           now: float) -> tuple[int, float]:
+    """Enqueue arrivals and advance virtual time until the scheduler
+    should flush or every arrival is in; returns (index of the next
+    arrival, now)."""
+    n = len(refs)
+    while True:
+        if sched.ready() == 0 and i < n:
+            now = max(now, refs[i].arrival_s)
+        while i < n and refs[i].arrival_s <= now:
+            sched.enqueue(refs[i])
+            i += 1
+        if i >= n or sched.should_flush(now):
+            return i, now
+        # advance virtual time to the next trigger: the next arrival or
+        # the oldest queued segment aging past max_wait; if the trigger
+        # cannot move time forward (fp boundary: at large virtual times
+        # `oldest + max_wait` can round to <= now), pack instead of
+        # spinning — `should_flush`'s ulp-relative tolerance makes the
+        # two sides of this boundary agree
+        t_next = refs[i].arrival_s
+        if sched.ready():
+            t_next = min(
+                t_next, sched.oldest_arrival() + sched.cfg.max_wait_s
+            )
+        if t_next <= now:
+            return i, now
+        now = t_next
+
+
 def simulate(
     cfg: FleetConfig,
     program: Optional[compiler.AcceleratorProgram] = None,
@@ -149,36 +179,41 @@ def simulate(
             program = compiler.compile_model(params)
         runner = FleetRunner(program, path=cfg.path, mesh=mesh)
 
-    source = FleetSource(cfg.source_config())
-    refs = (
-        check_refs(list(arrivals), cfg.n_patients)
-        if arrivals is not None
-        else source.arrivals(cfg.segments_per_patient)
-    )
-    sched = MicroBatchScheduler(cfg.scheduler_config(), cfg.n_patients)
-    if pinned_urgent is not None:
-        pinned_urgent = np.asarray(pinned_urgent, bool)
-        sched.set_urgent(pinned_urgent)
-    vstate = V.init(cfg.n_patients)
-    metrics = FleetMetrics()
-    bank = _SignalBank(source, refs) if cfg.pregen else None
-
-    # the vote cell is probe-tracked like the classify cells, so the
-    # repro.analysis cell audit covers it from the same registry
-    vote_update = obs.get().probe.track("stream.vote", V.update)
-
-    # warmup: compile every bucket shape outside the timed region
-    for b in cfg.buckets:
-        runner.classify(jnp.zeros((b, vadetect.RECORD_LEN))).block_until_ready()
-        vote_update(
-            vstate,
-            jnp.zeros((b,), jnp.int32),
-            jnp.zeros((b,), jnp.int32),
-            jnp.zeros((b,), bool),
-        )
-    metrics.start_clock()
     tel = obs.get()
+    # the call's set-up: arrival check, signal bank, warm-up; outside
+    # the loop clock
+    with tel.span("stream/setup", cat="stream"):
+        source = FleetSource(cfg.source_config())
+        refs = (
+            check_refs(list(arrivals), cfg.n_patients)
+            if arrivals is not None
+            else source.arrivals(cfg.segments_per_patient)
+        )
+        sched = MicroBatchScheduler(cfg.scheduler_config(), cfg.n_patients)
+        if pinned_urgent is not None:
+            pinned_urgent = np.asarray(pinned_urgent, bool)
+            sched.set_urgent(pinned_urgent)
+        vstate = V.init(cfg.n_patients)
+        metrics = FleetMetrics()
+        bank = _SignalBank(source, refs) if cfg.pregen else None
+
+        # the vote cell is probe-tracked like the classify cells, so the
+        # repro.analysis cell audit covers it from the same registry
+        vote_update = tel.probe.track("stream.vote", V.update)
+
+        # warmup: compile every bucket shape outside the timed region
+        for b in cfg.buckets:
+            runner.classify(
+                jnp.zeros((b, vadetect.RECORD_LEN))).block_until_ready()
+            vote_update(
+                vstate,
+                jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b,), bool),
+            )
+    metrics.start_clock()
     flush_hist = tel.registry.histogram("stream.flush_wall_s")
+    enqueued = tel.registry.counter("stream.enqueued_total")
 
     chip_s_per_patient = np.zeros(cfg.n_patients)
     final_diag = np.full(cfg.n_patients, -1, np.int64)
@@ -189,136 +224,135 @@ def simulate(
         if collect_latency
         else None
     )
+    # one `stream/step` span per packed batch; its children tile it:
+    # admit, pack, flush (gather, classify, vote), sync (each
+    # device-to-host read) and bookkeep
     i, now = 0, 0.0
     while i < len(refs) or sched.ready():
-        if sched.ready() == 0 and i < len(refs):
-            now = max(now, refs[i].arrival_s)
-        while i < len(refs) and refs[i].arrival_s <= now:
-            sched.enqueue(refs[i])
-            i += 1
-        drain = i >= len(refs)
-        if not drain and not sched.should_flush(now):
-            # advance virtual time to the next trigger: the next arrival
-            # or the oldest queued segment aging past max_wait; if the
-            # trigger cannot move time forward (fp boundary: at large
-            # virtual times `oldest + max_wait` can round to <= now),
-            # fall through and pack instead of spinning —
-            # `should_flush`'s ulp-relative tolerance makes the two
-            # sides of this boundary agree
-            t_next = refs[i].arrival_s
-            if sched.ready():
-                t_next = min(
-                    t_next, sched.oldest_arrival() + sched.cfg.max_wait_s
-                )
-            if t_next > now:
-                now = t_next
+        with tel.span("stream/step", cat="stream"):
+            with tel.span("stream/admit", cat="stream"):
+                i0 = i
+                i, now = _admit(sched, refs, i, now)
+                enqueued.add(i - i0)
+            batch = sched.next_batch(now)
+            if batch is None:
                 continue
-        batch = sched.next_batch(now)
-        if batch is None:
-            continue
-        # one rid list per batch, computed at pack time and shared by
-        # every hop the batch's segments take (flush / classify / vote)
-        # — the lineage join reads it back as `request_ids`
-        tagged = (
-            {"request_ids": batch.request_ids}
-            if batch.request_ids is not None
-            else {}
-        )
-        t_flush = time.perf_counter()
-        with tel.span(
-            "stream/flush", cat="stream",
-            bucket=batch.bucket, n_valid=batch.n_valid,
-            v_ts_s=now,
-            v_dur_s=runner.batch_service_s(batch.bucket),
-            **tagged,
-        ):
-            sigs = (
-                bank.gather(batch.patients, batch.seqs)
-                if bank is not None
-                else np.asarray(
-                    source.signals(batch.patients, batch.seqs)["signal"]
+            if tel.enabled:
+                # one rid list per batch, computed at pack time and
+                # shared by every hop the batch's segments take (flush /
+                # classify / vote) — the lineage join reads it back as
+                # `request_ids`
+                tagged = (
+                    {"request_ids": batch.request_ids}
+                    if batch.request_ids is not None
+                    else {}
                 )
-            )
-            with tel.span(
-                "stream/classify", cat="stream", bucket=batch.bucket,
-                v_ts_s=now, **tagged,
-            ):
-                preds = tel.block(runner.classify(jnp.asarray(sigs)))
-            with tel.span(
-                "stream/vote", cat="stream", v_ts_s=now, **tagged,
-            ):
-                # deliberately NOT tel.block()ed: the vote result is
-                # consumed (np.asarray) a few statements down, so the
-                # sync overlaps the host-side bookkeeping in both
-                # modes — blocking here would serialize that overlap
-                # only when telemetry is on and blow the <3% enabled
-                # budget. Wall dur is dispatch-only; the virtual track
-                # (v_ts_s/v_dur_s on the flush span) carries timing.
-                vstate, emit, diag, urgent = vote_update(
-                    vstate,
-                    jnp.asarray(batch.patients),
-                    preds,
-                    jnp.asarray(batch.valid),
+                flush_attrs = dict(
+                    bucket=batch.bucket, n_valid=batch.n_valid,
+                    v_ts_s=now,
+                    v_dur_s=runner.batch_service_s(batch.bucket),
+                    **tagged,
                 )
-        flush_hist.observe(time.perf_counter() - t_flush)
-        sched.set_urgent(
-            pinned_urgent
-            if pinned_urgent is not None
-            else np.asarray(urgent, bool)
-        )
-
-        service = runner.batch_service_s(batch.bucket)
-        # forced minimum progress: at adversarially large virtual times
-        # `now + service` can round back to exactly `now` (service below
-        # one ulp), freezing completion times for the rest of the run
-        completion = advance_virtual_time(now, now + service)
-        now = completion
-        valid = batch.valid
-        np.add.at(
-            chip_s_per_patient,
-            batch.patients[valid],
-            runner.chip_latency_s,
-        )
-        metrics.observe_batch(
-            bucket=batch.bucket,
-            n_valid=batch.n_valid,
-            n_urgent=int(
-                (batch.priorities[valid] == PRIORITY_URGENT).sum()
-            ),
-            slack_s=batch.deadlines[valid] - completion,
-            queue_depth=sched.ready(),
-            completion_s=completion,
-        )
-        if lat_records is not None:
-            lat_records["latency_s"].append(
-                completion - batch.arrivals[valid]
-            )
-            lat_records["slack_s"].append(
-                batch.deadlines[valid] - completion
-            )
-            lat_records["urgent"].append(
-                batch.priorities[valid] == PRIORITY_URGENT
-            )
-            lat_records["latency_from_pack_s"].append(
-                np.full(int(valid.sum()),
-                        completion - batch.formed_at_s)
-            )
-            lat_records["patient"].append(batch.patients[valid])
-        # masks/indices pinned: empty device results must never decay
-        # to float64 (the mark_urgent([]) class)
-        emit_np = np.asarray(emit, bool)
-        if emit_np.any():
-            diag_np = np.asarray(diag, np.int64)
-            who = np.nonzero(emit_np)[0]
-            metrics.observe_diagnoses(
-                len(who), int(diag_np[who].sum())
-            )
-            final_diag[who] = diag_np[who]
-            if collect_diagnoses:
-                diagnoses.extend(
-                    (int(p), int(diag_np[p]), float(completion))
-                    for p in who
+                classify_attrs = dict(
+                    bucket=batch.bucket, v_ts_s=now, **tagged)
+                vote_attrs = dict(v_ts_s=now, **tagged)
+            else:
+                flush_attrs = classify_attrs = vote_attrs = {}
+            t_flush = time.perf_counter()
+            with tel.span("stream/flush", cat="stream", **flush_attrs):
+                with tel.span("stream/gather", cat="stream"):
+                    if bank is not None:
+                        sigs = bank.gather(batch.patients, batch.seqs)
+                    else:
+                        sigs = np.asarray(source.signals(
+                            batch.patients, batch.seqs)["signal"])
+                        metrics.host_reads_total += 1
+                    sigs = jnp.asarray(sigs)
+                with tel.span(
+                    "stream/classify", cat="stream", **classify_attrs
+                ):
+                    preds = tel.block(runner.classify(sigs))
+                with tel.span("stream/vote", cat="stream", **vote_attrs):
+                    # deliberately NOT tel.block()ed: the vote result is
+                    # read (`stream/sync`) a few statements down, so
+                    # blocking here would only add a sync when telemetry
+                    # is on and blow the <3% enabled budget. Wall dur is
+                    # dispatch-only; the virtual track (v_ts_s/v_dur_s on
+                    # the flush span) carries timing.
+                    vstate, emit, diag, urgent = vote_update(
+                        vstate,
+                        jnp.asarray(batch.patients),
+                        preds,
+                        jnp.asarray(batch.valid),
+                    )
+            flush_hist.observe(time.perf_counter() - t_flush)
+            if pinned_urgent is None:
+                with tel.span("stream/sync", cat="stream"):
+                    urgent = np.asarray(urgent, bool)
+                    metrics.host_reads_total += 1
+            else:
+                urgent = pinned_urgent
+            with tel.span("stream/bookkeep", cat="stream"):
+                sched.set_urgent(urgent)
+                service = runner.batch_service_s(batch.bucket)
+                # forced minimum progress: at adversarially large virtual
+                # times `now + service` can round back to exactly `now`
+                # (service below one ulp), freezing completion times for
+                # the rest of the run
+                completion = advance_virtual_time(now, now + service)
+                now = completion
+                valid = batch.valid
+                np.add.at(
+                    chip_s_per_patient,
+                    batch.patients[valid],
+                    runner.chip_latency_s,
                 )
+                metrics.observe_batch(
+                    bucket=batch.bucket,
+                    n_valid=batch.n_valid,
+                    n_urgent=int(
+                        (batch.priorities[valid] == PRIORITY_URGENT).sum()
+                    ),
+                    slack_s=batch.deadlines[valid] - completion,
+                    queue_depth=sched.ready(),
+                    completion_s=completion,
+                )
+                if lat_records is not None:
+                    lat_records["latency_s"].append(
+                        completion - batch.arrivals[valid]
+                    )
+                    lat_records["slack_s"].append(
+                        batch.deadlines[valid] - completion
+                    )
+                    lat_records["urgent"].append(
+                        batch.priorities[valid] == PRIORITY_URGENT
+                    )
+                    lat_records["latency_from_pack_s"].append(
+                        np.full(int(valid.sum()),
+                                completion - batch.formed_at_s)
+                    )
+                    lat_records["patient"].append(batch.patients[valid])
+            with tel.span("stream/sync", cat="stream"):
+                # masks/indices pinned: empty device results must never
+                # decay to float64 (the mark_urgent([]) class)
+                emit_np = np.asarray(emit, bool)
+                metrics.host_reads_total += 1
+                diag_np = None
+                if emit_np.any():
+                    diag_np = np.asarray(diag, np.int64)
+                    metrics.host_reads_total += 1
+            if diag_np is not None:
+                with tel.span("stream/bookkeep", cat="stream"):
+                    who = np.nonzero(emit_np)[0]
+                    metrics.observe_diagnoses(
+                        len(who), int(diag_np[who].sum())
+                    )
+                    final_diag[who] = diag_np[who]
+                    if collect_diagnoses:
+                        diagnoses.extend(
+                            (int(p), int(diag_np[p]), float(completion))
+                            for p in who
+                        )
     metrics.stop_clock()
 
     metrics.dropped_total = sched.enqueued_total - sched.packed_total

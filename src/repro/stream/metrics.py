@@ -42,6 +42,7 @@ class FleetMetrics:
     va_diagnoses_total: int = 0
     urgent_packed_total: int = 0
     dropped_total: int = 0  # scheduler drops — must stay 0
+    host_reads_total: int = 0  # device-to-host reads in the fleet loop
     virtual_horizon_s: float = 0.0  # last modeled completion time
 
     def __post_init__(self):
@@ -123,6 +124,7 @@ class FleetMetrics:
             "va_diagnoses_total": self.va_diagnoses_total,
             "urgent_packed_total": self.urgent_packed_total,
             "dropped_total": self.dropped_total,
+            "host_reads_total": self.host_reads_total,
             "wall_s": wall,
             "segments_per_s_wall": self.segments_total / wall,
             "diagnoses_per_s_wall": self.diagnoses_total / wall,

@@ -115,8 +115,9 @@ class MicroBatchScheduler:
             self._oldest_cache = ref.arrival_s
         self._queue.append((next(self._tie), ref))
         self.enqueued_total += 1
+        # the registry's `stream.enqueued_total` is the caller's to add
+        # (`simulate` once per admission span)
         tel = obs.get()
-        tel.registry.counter("stream.enqueued_total").inc()
         if tel.enabled:
             # lineage root: mints the segment's request id at admission
             # with its *intended* arrival on the virtual track
@@ -218,10 +219,11 @@ class MicroBatchScheduler:
         if not self._queue:
             return None
         tel = obs.get()
-        with tel.span(
-            "stream/pack", cat="stream",
-            queue_depth=len(self._queue), v_ts_s=now_s,
-        ) as sp:
+        attrs = (
+            {"queue_depth": len(self._queue), "v_ts_s": now_s}
+            if tel.enabled else {}
+        )
+        with tel.span("stream/pack", cat="stream", **attrs) as sp:
             batch = self._pack(now_s)
             if tel.enabled:
                 # which segments this pack chose is only known now —
@@ -235,8 +237,6 @@ class MicroBatchScheduler:
                     f"stream:{p}:{s}" for p, s in zip(ps, ss)
                 ]
                 sp.set(request_ids=batch.request_ids)
-        tel.registry.counter("stream.packed_total").inc(batch.n_valid)
-        tel.registry.gauge("stream.queue_depth").set(len(self._queue))
         return batch
 
     def _pack(self, now_s: float) -> PackedBatch:

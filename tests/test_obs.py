@@ -25,6 +25,7 @@ from repro.core import compiler, vadetect
 from repro.models import api
 from repro.obs.registry import PER_DECADE, Histogram
 from repro.serve import engine as E
+from repro.serve.paging import PagingConfig, validate_page_size
 from repro.stream import FleetConfig, FleetRunner, simulate
 
 
@@ -213,6 +214,83 @@ def test_telemetry_section_schema():
     assert h["count"] == 1 and h["p50"] is not None
     assert "x.cell" in sec["recompiles"]
     assert sec["peak_device_memory_bytes"] >= keep.nbytes
+
+
+# ---------------------------------------------------------------------------
+# profiler sink: the program's spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled_span_names(trace_dir) -> list:
+    """Names of the `stream/*` and `serve/*` events on the host planes
+    of the profiler trace under `trace_dir`."""
+    from jax.profiler import ProfileData
+
+    (path,) = sorted(trace_dir.glob("**/*.xplane.pb"))
+    names = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names += [e.name for e in line.events
+                          if e.name.startswith(("stream/", "serve/"))]
+    return names
+
+
+def _paged_engine(batch_size: int):
+    cfg = configs.reduced("qwen3_8b")
+    model = api.build_model(cfg, tp=1, max_seq=32)
+    params = model.init(jax.random.PRNGKey(0))
+    span = validate_page_size(8, model.attn_capacities())
+    eng = E.Engine(model, params, batch_size=batch_size,
+                   paging=PagingConfig(8, batch_size * span + 1))
+    return eng, cfg
+
+
+def test_disabled_spans_reach_the_profiler(program, tmp_path):
+    """With telemetry off, the fleet loop's and the decode tick's spans
+    are written onto a host plane of a `jax.profiler` trace: one
+    `stream/step` per packed batch, its children, and the tick's."""
+    assert not obs.get().enabled
+    cfg = FleetConfig(n_patients=8, segments_per_patient=6,
+                      buckets=(4, 16), seed=2)
+    runner = FleetRunner(program, path="twin")
+    eng, lm = _paged_engine(batch_size=2)
+    for uid in range(2):
+        eng.submit(E.Request(
+            uid=uid, prompt=jax.random.randint(
+                jax.random.PRNGKey(uid), (6,), 0, lm.vocab),
+            max_new=4))
+    eng.tick()  # compiles admission and decode outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        out = simulate(cfg, runner=runner)
+        for _ in range(2):
+            eng.tick()
+    names = _profiled_span_names(tmp_path)
+    batches = out["metrics"]["batches_total"]
+    assert names.count("stream/step") == batches
+    assert names.count("stream/pack") == batches
+    assert names.count("stream/admit") == batches
+    assert names.count("stream/gather") == batches
+    assert names.count("stream/setup") == 1
+    # urgent, and emit (with diag where a vote fired): two sync spans
+    assert names.count("stream/sync") == 2 * batches
+    for name in ("stream/flush", "stream/classify", "stream/vote",
+                 "stream/bookkeep", "serve/admission", "serve/decode"):
+        assert name in names, name
+    for name in ("serve/tick", "serve/emit", "serve/pages"):
+        assert names.count(name) == 2, name
+
+
+def test_enabled_span_lands_in_both_sinks(tmp_path):
+    """One `tel.span` with the JSONL tracer on feeds the JSONL log (with
+    its attributes) and the profiler's trace (by its bare name)."""
+    tel = obs.configure(enabled=True)
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with tel.span("serve/tick", cat="serve", n=3):
+            jnp.ones(4).block_until_ready()
+    assert _profiled_span_names(tmp_path / "prof") == ["serve/tick"]
+    (ev,) = tel.tracer.events()
+    assert ev["name"] == "serve/tick" and ev["attrs"] == {"n": 3}
 
 
 # ---------------------------------------------------------------------------

@@ -74,6 +74,32 @@ def test_engine_slots_and_recycling():
         assert r.done and len(r.output) == 4
 
 
+@pytest.mark.parametrize("k", [1, 3])
+def test_engine_host_reads_counted(k):
+    """`Engine.host_reads` counts the tick's device-to-host reads:
+    admission reads each seated request's first token and its prompt's
+    last token, and each tick one token per occupied slot — a tick with
+    k occupied slots and no admission adds k."""
+    from repro.serve.paging import PagingConfig, validate_page_size
+
+    cfg = configs.reduced("qwen3_8b")
+    model = api.build_model(cfg, tp=1, max_seq=32)
+    params = model.init(jax.random.PRNGKey(0))
+    span = validate_page_size(8, model.attn_capacities())
+    eng = E.Engine(model, params, batch_size=4,
+                   paging=PagingConfig(8, 4 * span + 1))
+    for uid in range(k):
+        eng.submit(E.Request(
+            uid=uid, prompt=jax.random.randint(
+                jax.random.PRNGKey(uid), (5,), 0, cfg.vocab),
+            max_new=8))
+    eng.tick()
+    assert eng.host_reads == 2 * k + k
+    for n in range(2, 5):
+        assert eng.tick() == k
+        assert eng.host_reads == 2 * k + n * k
+
+
 def test_engine_eos_on_first_token_recycles_slot():
     """Regression: a request finishing on the same tick it was admitted
     (EOS as its very first generated token) must not leak its slot —

@@ -511,6 +511,27 @@ def test_fleet_simulation_deterministic_no_drops(program):
         b["metrics"]["deadline_slack_s"]["violations"]
 
 
+@pytest.mark.parametrize("pregen,pinned", [
+    (True, False), (True, True), (False, False),
+])
+def test_fleet_host_reads_counted(program, pregen, pinned):
+    """`host_reads_total` counts every device-to-host read of the loop:
+    the vote's urgency bitmap (unless pinned) and emission mask each
+    batch, its diagnoses in each batch where a vote fired, and the
+    synthesised signals when they are not pre-materialised."""
+    cfg = FleetConfig(n_patients=12, segments_per_patient=6,
+                      buckets=(4, 16), va_fraction=0.4, seed=11,
+                      pregen=pregen)
+    out = simulate(cfg, program, collect_diagnoses=True,
+                   pinned_urgent=np.zeros(12, bool) if pinned else None)
+    m = out["metrics"]
+    # completion times rise batch by batch: one per batch that voted
+    voted = len({t for *_, t in out["diagnoses"]})
+    assert voted > 0
+    per_batch = 1 + (not pinned) + (not pregen)
+    assert m["host_reads_total"] == per_batch * m["batches_total"] + voted
+
+
 def test_should_flush_fp_boundary_at_large_virtual_times():
     """Regression: the flush predicate must hold at now == oldest +
     max_wait even when fp cancellation rounds the recovered wait below
