@@ -288,9 +288,8 @@ class Engine:
         # request here
         self.admission_rowsteps = 0
         self.admission_prefills = 0
-        # device-to-host reads the engine made (`_to_host`): a token per
-        # occupied slot per tick, a first token and a prompt's last
-        # token per admission
+        # device-to-host reads the engine made (`_to_host`): the pool's
+        # tokens once per tick, the first tokens once per admission group
         self.host_reads = 0
         # request latency tracking (wall): submit time per uid until the
         # first token, then last-token time per slot for inter-token
@@ -583,9 +582,10 @@ class Engine:
                 ),
                 temperature=self.temperature, top_k=self.top_k,
             )
+        firsts = self._to_host(firsts)
         src, dst = [], []
         for j, (slot, req) in enumerate(pairs):
-            first = int(self._to_host(firsts[j]))
+            first = int(firsts[j])
             req.output.append(first)
             if tel.enabled:
                 t_now = time.perf_counter()
@@ -651,8 +651,7 @@ class Engine:
         self.pos = self.pos.at[slot].set(s_len - 1)
         self.tokens = self.tokens.at[slot].set(first)
         self.active = self.active.at[slot].set(True)
-        self._ctok = self._ctok.at[slot].set(
-            int(self._to_host(req.prompt[-1])))
+        self._ctok = self._ctok.at[slot].set(req.prompt[-1])
         self._cpos = self._cpos.at[slot].set(s_len - 1)
         self._slot_keys = self._slot_keys.at[slot].set(
             request_key(self.key, req.uid)
@@ -925,14 +924,15 @@ class Engine:
             self._hpos[slot] = nw
 
     def _emit(self, tel, nxt: jax.Array) -> int:
-        """Each occupied slot's token from the decode (a host read
-        each); finished requests free their slot and pages. Returns the
-        slots still active."""
+        """Each occupied slot's token from the decode (one host read of
+        the whole pool); finished requests free their slot and pages.
+        Returns the slots still active."""
+        toks = self._to_host(nxt)
         n_active = 0
         for slot, req in enumerate(self._slots):
             if req is None:
                 continue
-            tok = int(self._to_host(nxt[slot]))
+            tok = int(toks[slot])
             req.output.append(tok)
             if tel.enabled:
                 t_now = time.perf_counter()

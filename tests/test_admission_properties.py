@@ -31,6 +31,7 @@ from repro.models import api
 from repro.serve import engine as E
 from repro.serve import seating
 from repro.serve import sharded as SH
+from slot_layouts import PINNED, drive_pinned, pinned_requests
 
 # one attention family + both recurrent families: the archs whose
 # engine admission the scatter-seat refactor changed most
@@ -120,7 +121,13 @@ def _drive_random_interleaving(eng, reqs, rng, max_steps=200):
     assert steps < max_steps, "interleaving did not drain"
 
 
-@pytest.mark.parametrize("name", ARCHS)
+INTERLEAVING_CASES = [
+    *[pytest.param(n, None, id=n) for n in ARCHS],
+    *[pytest.param("qwen3_8b", c, id=f"qwen3_8b-{c}") for c in PINNED],
+]
+
+
+@pytest.mark.parametrize("name,case", INTERLEAVING_CASES)
 @settings(max_examples=5, deadline=None)
 @given(
     batch_size=st.sampled_from([2, 3]),
@@ -128,14 +135,26 @@ def _drive_random_interleaving(eng, reqs, rng, max_steps=200):
     seed=st.integers(0, 10_000),
 )
 def test_engine_matches_generate_under_random_interleavings(
-    built, name, batch_size, n_reqs, seed
+    built, name, case, batch_size, n_reqs, seed
 ):
     """The scatter-seated engine is token-for-token identical to the
     prefill+decode generate path, for every request, under random
     admit/tick interleavings — including recurrent-cache models at
-    batch_size > 1 (the lifted PR 3 guard)."""
+    batch_size > 1 (the lifted PR 3 guard) — and under the pinned slot
+    layouts (`PINNED`, which fix their own batch and requests)."""
     model, params, FastEngine, prefill, decode = built[name]
     rng = np.random.default_rng(seed)
+    if case is not None:
+        reqs, late = pinned_requests(
+            model.cfg, rng, case,
+            lambda r: _ref_stream(prefill, decode, params, r),
+        )
+        eng = FastEngine(model, params, batch_size=len(reqs))
+        for r in drive_pinned(eng, reqs, late, case):
+            assert r.done, r.uid
+            ref = _ref_stream(prefill, decode, params, r)
+            assert r.output == ref, (case, r.uid, r.output, ref)
+        return
     # EOS drawn from the first request's own reference stream, so EOS
     # cuts (including EOS-on-first-token) actually trigger sometimes
     probe = _ref_stream(
@@ -274,31 +293,49 @@ def test_sampling_reproducible_across_runs_and_seat_order(built):
     assert a != d
 
 
-def test_engine_sampling_matches_generate_schedule(built):
-    """With uid == row index and one co-admitted batch, the engine's
-    per-request folded keys reproduce `generate`'s sampled streams
-    token-for-token."""
+@pytest.mark.parametrize("case", ["co_admitted", *PINNED])
+def test_engine_sampling_matches_generate_schedule(built, case):
+    """With uid == row index, the engine's per-request folded keys
+    reproduce `generate`'s sampled streams token-for-token: for one
+    co-admitted batch, and under the pinned slot layouts (`PINNED`),
+    where each request's stream is its `generate` row cut at its EOS or
+    `max_new`."""
     model, params, FastEngine, _, _ = built["qwen3_8b"]
     key = jax.random.PRNGKey(21)
+    # `max_new` of the co-admitted rows, then of the late arrival or None
+    *max_new, late_new = PINNED[case][0] if case in PINNED else [4, 4, None]
+    news = max_new + ([late_new] if late_new is not None else [])
     prompts = jax.random.randint(
-        jax.random.PRNGKey(5), (2, 3), 0, model.cfg.vocab
+        jax.random.PRNGKey(5), (len(news), 3), 0, model.cfg.vocab
     )
     ref = np.asarray(E.generate(
-        model, params, prompts, max_new=4, greedy=False, key=key,
-        temperature=0.8, top_k=5,
-    ))
+        model, params, prompts, max_new=max(news), greedy=False,
+        key=key, temperature=0.8, top_k=5,
+    )).tolist()
     eng = FastEngine(
-        model, params, batch_size=2, greedy=False,
+        model, params, batch_size=len(max_new), greedy=False,
         temperature=0.8, top_k=5, key=key,
     )
     reqs = [
-        E.Request(uid=i, prompt=prompts[i], max_new=4) for i in range(2)
+        E.Request(uid=i, prompt=prompts[i], max_new=m)
+        for i, m in enumerate(news)
     ]
-    for r in reqs:
-        eng.submit(r)
-    eng.run(max_ticks=20)
+    if case == "eos_between":
+        cut = next(k for k in range(1, len(ref[1]))
+                   if ref[1][k] not in ref[1][:k])
+        reqs[1].eos = ref[1][cut]
+    if case == "co_admitted":
+        for r in reqs:
+            eng.submit(r)
+        eng.run(max_ticks=20)
+    else:
+        late = reqs.pop() if late_new is not None else None
+        reqs = drive_pinned(eng, reqs, late, case)
     for i, r in enumerate(reqs):
-        assert r.output == ref[i].tolist(), (r.output, ref[i].tolist())
+        want = ref[i][:r.max_new]
+        if r.eos is not None:
+            want = want[:want.index(r.eos) + 1]
+        assert r.done and r.output == want, (case, i, r.output, want)
 
 
 def test_sample_tokens_topk_edge_cases():
@@ -505,6 +542,39 @@ def test_sharded_engine_matches_sharded_generate_under_interleavings(
         assert r.output == ref, (name, mesh_shape, r.uid, r.output, ref)
     assert all(s is None for s in eng._slots)
     assert not bool(eng.active.any())
+
+
+@pytest.mark.slow
+@multidevice
+@pytest.mark.parametrize("mesh_shape", [(8, 1), (4, 2)])
+def test_sharded_engine_reads_tokens_once_per_tick(built, mesh_shape):
+    """`ShardedEngine` inherits the engine's single read of a tick's
+    tokens: with all 8 slots occupied, a tick adds exactly one host read
+    (the batch-sharded tokens gathered in one transfer), an admission
+    group one more, and every stream still matches the
+    `sharded_generate` cells on the same mesh."""
+    from repro.launch.mesh import make_smoke_mesh
+
+    model, params, _, _, _ = built["qwen3_8b"]
+    mesh = make_smoke_mesh(*mesh_shape)
+    cells = _mesh_ref_cells(model, params, mesh)
+    reqs = [
+        E.Request(uid=i, prompt=jax.random.randint(
+            jax.random.PRNGKey(1000 + i), (3,), 0, model.cfg.vocab
+        ), max_new=5)
+        for i in range(8)
+    ]
+    eng = SH.ShardedEngine(model, params, batch_size=8, mesh=mesh)
+    for r in reqs:
+        eng.submit(r)
+    for n in range(1, 4):
+        assert eng.tick() == 8
+        assert eng.host_reads == 1 + n
+    eng.run(max_ticks=10)
+    for r in reqs:
+        assert r.done
+        ref = _mesh_ref_stream(cells, r)
+        assert r.output == ref, (mesh_shape, r.uid, r.output, ref)
 
 
 @pytest.mark.slow

@@ -47,6 +47,7 @@ from repro.serve.paging import (
     pages_for_position,
     validate_page_size,
 )
+from slot_layouts import PINNED, drive_pinned, pinned_requests
 
 ARCHS = ("qwen3_8b", "recurrentgemma_2b", "rwkv6_3b")
 
@@ -173,7 +174,10 @@ def _drive_random_interleaving(eng, reqs, rng, max_steps=200):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("name", ARCHS)
+@pytest.mark.parametrize("name,case", [
+    *[pytest.param(n, None, id=n) for n in ARCHS],
+    *[pytest.param("qwen3_8b", c, id=f"qwen3_8b-{c}") for c in PINNED],
+])
 @settings(max_examples=4, deadline=None)
 @given(
     batch_size=st.sampled_from([2, 3]),
@@ -181,13 +185,35 @@ def _drive_random_interleaving(eng, reqs, rng, max_steps=200):
     seed=st.integers(0, 10_000),
 )
 def test_paged_engine_matches_dense_and_generate(
-    built, name, batch_size, n_reqs, seed
+    built, name, case, batch_size, n_reqs, seed
 ):
     """Token-for-token: paged engine == dense engine == solo reference,
     for every request, under the same random admit/tick interleaving —
     with prompts both shorter than a page and crossing page
-    boundaries, EOS cuts, and slot/page recycling."""
+    boundaries, EOS cuts, and slot/page recycling — and under the
+    pinned slot layouts (`slot_layouts.PINNED`)."""
     model, params, FastEngine, prefill, decode = built[name]
+    if case is not None:
+        streams = {}
+        for label, kw in (
+            ("dense", {}), ("paged", {"paging": PAGING}),
+        ):
+            reqs, late = pinned_requests(
+                model.cfg, np.random.default_rng(seed), case,
+                lambda r: _ref_stream(prefill, decode, params, r),
+            )
+            eng = FastEngine(model, params, batch_size=len(reqs), **kw)
+            reqs = drive_pinned(eng, reqs, late, case)
+            if eng._pg is not None:
+                eng._pg.check_invariants()
+                assert eng._pg.allocated_pages() == 0, "pages leaked"
+            streams[label] = {r.uid: r.output for r in reqs}
+        assert streams["paged"] == streams["dense"]
+        for r in reqs:
+            assert r.done, r.uid
+            ref = _ref_stream(prefill, decode, params, r)
+            assert r.output == ref, (case, r.uid, r.output, ref)
+        return
     probe = _ref_stream(
         prefill, decode, params,
         E.Request(uid=0, prompt=jax.random.randint(

@@ -74,12 +74,17 @@ def test_engine_slots_and_recycling():
         assert r.done and len(r.output) == 4
 
 
-@pytest.mark.parametrize("k", [1, 3])
-def test_engine_host_reads_counted(k):
-    """`Engine.host_reads` counts the tick's device-to-host reads:
-    admission reads each seated request's first token and its prompt's
-    last token, and each tick one token per occupied slot — a tick with
-    k occupied slots and no admission adds k."""
+@pytest.mark.parametrize("k,short", [
+    pytest.param(1, None, id="1"),
+    pytest.param(3, None, id="3"),
+    # the middle request finishes on tick 2 while its neighbours decode
+    pytest.param(3, 1, id="3-finish"),
+])
+def test_engine_host_reads_counted(k, short):
+    """`Engine.host_reads` counts the tick's device-to-host reads: one
+    for an admission group's first tokens, one per tick for the whole
+    pool's tokens, and none for a prompt's last token. A slot that
+    finishes is freed from that same single read."""
     from repro.serve.paging import PagingConfig, validate_page_size
 
     cfg = configs.reduced("qwen3_8b")
@@ -88,16 +93,27 @@ def test_engine_host_reads_counted(k):
     span = validate_page_size(8, model.attn_capacities())
     eng = E.Engine(model, params, batch_size=4,
                    paging=PagingConfig(8, 4 * span + 1))
-    for uid in range(k):
-        eng.submit(E.Request(
-            uid=uid, prompt=jax.random.randint(
-                jax.random.PRNGKey(uid), (5,), 0, cfg.vocab),
-            max_new=8))
-    eng.tick()
-    assert eng.host_reads == 2 * k + k
+    reqs = [
+        E.Request(uid=uid, prompt=jax.random.randint(
+            jax.random.PRNGKey(uid), (5,), 0, cfg.vocab),
+            max_new=3 if uid == short else 8)
+        for uid in range(k)
+    ]
+    for r in reqs:
+        eng.submit(r)
+    assert eng.tick() == k
+    assert eng.host_reads == 1 + 1
+    occupied = k
     for n in range(2, 5):
-        assert eng.tick() == k
-        assert eng.host_reads == 2 * k + n * k
+        if n == 2 and short is not None:
+            occupied -= 1
+        assert eng.tick() == occupied
+        assert eng.host_reads == 1 + n
+    if short is not None:
+        assert reqs[short].done and len(reqs[short].output) == 3
+        assert eng._slots[short] is None
+        assert not bool(eng.active[short])
+        assert all(eng._slots[i] is reqs[i] for i in range(k) if i != short)
 
 
 def test_engine_eos_on_first_token_recycles_slot():
