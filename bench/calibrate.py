@@ -60,7 +60,7 @@ def sweep(spec, rates, seconds, device, peak, path) -> None:
 
     ctx = R.Context(spec.cfg, spec.mix, spec.ref, 1, seconds,
                     R.seed_key(1), peak, spec.limits, R.Tracer(None),
-                    loadgen, {})
+                    loadgen, {}, spec.chips)
     cell = spec.system.Cell(ctx)
     cell.setup()
     for i, rate in enumerate(rates):

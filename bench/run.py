@@ -213,6 +213,7 @@ class Context:
     tracer: Tracer
     loadgen: Any
     faults: dict
+    chips: int  # the cell's `chips`: the system builds its mesh over these
 
     def span(self, name: str):
         return self.tracer.span(name)
@@ -245,9 +246,18 @@ def device_info(chips: int) -> dict:
         raise SetupError(
             f"no TPU found: JAX sees {len(devs)} {d0.platform} device(s) "
             f"({d0.device_kind}); the benchmark runs on a TPU only")
+    return devices_for(chips)
+
+
+def devices_for(chips: int) -> dict:
+    """Platform, kind and count of JAX's devices, of whatever platform;
+    a `SetupError` unless there are at least `chips` of them."""
+    import jax
+
+    devs = jax.devices()
     if len(devs) < chips:
         raise SetupError(f"the cell needs {chips} chips, found {len(devs)}")
-    return {"platform": d0.platform, "kind": d0.device_kind,
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
 
 
@@ -269,6 +279,7 @@ class Reading:
     trace: Optional[dict]
     peak: dict
     end_to_end: dict
+    chips: int  # the cell's `chips`, to divide work or peak by
 
 
 def say(msg: str) -> None:
@@ -287,7 +298,7 @@ def run_cell(spec: CellSpec, seed: int, seconds: float, trace: bool, *,
                     min(TRACE_SECONDS, seconds))
     ctx = Context(spec.cfg, spec.mix, spec.ref, seed, seconds,
                   seed_key(seed), peak, spec.limits, tracer, loadgen,
-                  dict(faults or {}))
+                  dict(faults or {}), spec.chips)
     cell = spec.system.Cell(ctx)
     cell.setup()
     counter = CompileCounter()
@@ -315,7 +326,8 @@ def run_cell(spec: CellSpec, seed: int, seconds: float, trace: bool, *,
         c.setdefault("ok", c["value"] <= c["limit"])
     attempted, failed = cell.attempted_failed()
     if trace:
-        reading = Reading(cell.counters(), cell.work(), reduced, peak, e2e)
+        reading = Reading(cell.counters(), cell.work(), reduced, peak, e2e,
+                          spec.chips)
         metrics = {}
         for m in spec.per_layer:
             v = spec.readers[m["name"]].read(reading)

@@ -82,3 +82,86 @@ def test_recorded_chip_trace():
     # the host sleeps between calls: the gaps open outside the spans
     assert r["idle_gaps"][0][0] == "outside bench spans"
     assert r["idle_gaps"][0][1] >= 0.002
+
+
+# Op events as XLA:TPU names them in the sharded qwen3-8b decode step and
+# admission prefill on a 2x2 mesh (HLO text, shapes cut short)
+ASYNC_START = ("%async-collective-start = (bf16[16,128,2048]) "
+               "fusion(%bitcast.300), kind=kCustom, "
+               "calls=%fused_computation.170")
+ASYNC_DONE = ("%async-collective-done.1 = bf16[16,128,4096] "
+              "fusion(%get-tuple-element.1007), kind=kCustom, "
+              "calls=%fused_computation.177")
+SCATTER_FUSION = ("%fusion.168 = (bf16[256,8,128]) fusion(%fusion.165), "
+                  "kind=kCustom, calls=%all-reduce-scatter.3.clone.clone")
+OVERLAP_FUSION = ("%fusion.186 = (f32[8,4]) fusion(%get-tuple-element.981),"
+                  " kind=kOutput, calls=%async_collective_fusion.186")
+
+
+def _four_devices():
+    """Four device planes, one module: a plain fusion, an all-reduce, an
+    asynchronous all-gather whose start and done events bracket compute,
+    an all-reduce-scatter fusion, and a compute fusion overlapping an
+    asynchronous collective; one event outside the window."""
+    ms = 1e6
+    devices = {}
+    for d in range(4):
+        ops = [("%fusion.7 = f32[8] fusion(x)", 0 * ms, 2 * ms),
+               ("all-reduce.3", 2 * ms, (3 + d) * ms),
+               ("all-gather-start.1", 8 * ms, 9 * ms),
+               ("fusion.7", 9 * ms, 10 * ms),
+               ("all-gather-done.1", 10 * ms, 10.5 * ms),
+               (ASYNC_START, 7 * ms, 7.25 * ms),
+               (OVERLAP_FUSION, 7.25 * ms, 7.5 * ms),
+               (ASYNC_DONE, 7.5 * ms, 7.75 * ms),
+               (SCATTER_FUSION, 10.5 * ms, 11.5 * ms),
+               ("all-reduce.3", 12 * ms, 13 * ms)]
+        mods = [("jit_step(5)", 0 * ms, 13 * ms)]
+        devices[f"/device:TPU:{d}"] = {"XLA Ops": ops, "XLA Modules": mods}
+    return {"devices": devices,
+            "spans": [("bench/window", 0 * ms, 11 * ms)]}
+
+
+def test_collective_time_by_executable():
+    r = T.reduce(_four_devices())
+    # device d: all-reduce 1 + d ms, all-gather start 1 and done 0.5 ms,
+    # the asynchronous collective's start and done 0.25 ms each (not the
+    # compute between), the scatter fusion's first 0.5 ms; the last
+    # all-reduce and the rest of the scatter fusion lie after the window
+    assert list(r["collectives_s"]) == ["jit_step"]
+    assert r["collectives_s"]["jit_step"] == pytest.approx(
+        sum(1 + d + 1.5 + 0.5 + 0.5 for d in range(4)) / 4 * 1e-3)
+    assert r["executables_s"]["jit_step"] == pytest.approx(11e-3)
+
+
+def test_busy_time_by_device():
+    r = T.reduce(_four_devices())
+    # device d: 0..3+d ms, then 7..7.75 and 8..11 ms
+    want = {f"/device:TPU:{d}": (3 + d + 0.75 + 3) * 1e-3 for d in range(4)}
+    assert r["busy_by_device_s"] == pytest.approx(want)
+    assert r["busy_s"] == pytest.approx(sum(want.values()) / 4)
+    assert r["n_devices"] == 4
+
+
+def test_collective_names():
+    for op in ("all-reduce.3", "all-gather-start.1", "all-gather-done.1",
+               "reduce-scatter.2", "collective-permute-done",
+               "%all-to-all.5 = bf16[2,8,1,2048] all-to-all(%all-reduce.11)",
+               "all-reduce-scatter-fusion.1", ASYNC_START, ASYNC_DONE,
+               SCATTER_FUSION):
+        assert T.is_collective(op), op
+    for op in ("fusion.7", "copy.2", "reduce.1", "scatter.3", "while.5",
+               "%fusion.22 = bf16[256] fusion(%copy.2), kind=kOutput, "
+               "calls=%fused_computation.14", OVERLAP_FUSION):
+        assert not T.is_collective(op), op
+
+
+def test_recorded_chip_trace_has_no_collectives():
+    r = T.reduce(T.read(RECORDED))
+    assert r["collectives_s"] == {}
+    assert r["busy_by_device_s"] == {"/device:TPU:0": r["busy_s"]}
+
+
+def test_empty_reduction_has_every_key():
+    r = T.reduce(_raw())
+    assert set(T.empty(1.0)) == set(r)

@@ -11,6 +11,17 @@ per-layer metrics read.
   JAX names a jitted function.
 - Device time per operation is keyed `<module>/<op>`, the op being the
   name its HLO text gives it (`fusion.28`).
+- Collective time per executable is the union of the events of the
+  collective ops, clipped to the window and averaged over the devices:
+  the exposed time of those ops' own events. An op is collective when
+  its name starts with a collective's (`all-reduce.3`,
+  `all-gather-start.1`, `collective-permute-done`, and XLA:TPU's
+  `async-collective-start`/`-done` fusions), or when it is a fusion
+  that calls a computation so named (`fusion.168` calling
+  `%all-reduce-scatter.3`). An asynchronous collective in flight
+  between its start and done while other ops run is not in it, nor is
+  a compute fusion that overlaps one (`calls=%async_collective_fusion.N`).
+- Busy time of each device is kept by its plane's name.
 - The longest idle gaps on the first device are attributed to the
   innermost harness span (`bench/...` on a host thread) open when the
   gap began.
@@ -31,8 +42,12 @@ MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench/"
 WINDOW_SPAN = "bench/window"
 TOP = 10
+COLLECTIVE_PREFIXES = ("all-gather", "all-reduce", "reduce-scatter",
+                       "collective-permute", "all-to-all",
+                       "async-collective-")
 
 _ID_SUFFIX = re.compile(r"\(\d+\)$")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -51,6 +66,15 @@ def op_name(event_name: str) -> str:
     """`fusion.28` from an op event named by its HLO text
     (`%fusion.28 = bf16[...] fusion(...)`)."""
     return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def is_collective(event_name: str) -> bool:
+    """Whether an op event, named by its HLO text, is a collective: by
+    its own name or by the computation it calls."""
+    if op_name(event_name).startswith(COLLECTIVE_PREFIXES):
+        return True
+    called = _CALLS.search(event_name)
+    return bool(called) and called.group(1).startswith(COLLECTIVE_PREFIXES)
 
 
 def merge(intervals: list) -> list:
@@ -94,8 +118,9 @@ def read(path: str) -> dict:
 
 
 def reduce(raw: dict) -> dict:
-    """Busy and idle time, device time per executable and per op, and
-    the longest idle gaps with the span open at each."""
+    """Busy and idle time (averaged and per device), device time per
+    executable, per op and in collectives, and the longest idle gaps
+    with the span open at each."""
     devices, spans = raw["devices"], raw["spans"]
     if not devices:
         raise ValueError("the trace holds no TPU device plane")
@@ -108,6 +133,7 @@ def reduce(raw: dict) -> dict:
         lo, hi = min(s for s, _ in ends), max(e for _, e in ends)
     window_ns = hi - lo
     busy, per_module, per_op = [], defaultdict(float), defaultdict(float)
+    per_coll = defaultdict(float)
     first = None
     for name in sorted(devices):
         lines = devices[name]
@@ -118,13 +144,19 @@ def reduce(raw: dict) -> dict:
             first = merged
         mods = sorted(lines.get(MODULES_LINE, []), key=lambda m: m[1])
         starts = [m[1] for m in mods]
+        coll = defaultdict(list)
         for op, s, e in ops:
             if e > lo and s < hi:
                 i = bisect.bisect_right(starts, s) - 1
-                mod = (module_name(mods[i][0]) + "/"
+                mod = (module_name(mods[i][0])
                        if i >= 0 and s < mods[i][2] else "")
-                per_op[mod + op_name(op)] += (
+                per_op[(mod + "/" if mod else "") + op_name(op)] += (
                     (min(e, hi) - max(s, lo)) / len(devices))
+                if is_collective(op):
+                    coll[mod].append([s, e])
+        for mod, ivs in coll.items():
+            per_coll[mod] += sum(
+                e - s for s, e in clip(merge(ivs), lo, hi)) / len(devices)
         for mod, s, e in mods:
             if e > lo and s < hi:
                 per_module[module_name(mod)] += (
@@ -142,6 +174,9 @@ def reduce(raw: dict) -> dict:
         "busy_s": busy_ns * 1e-9,
         "idle_share": 1.0 - busy_ns / window_ns if window_ns else None,
         "executables_s": {k: v * 1e-9 for k, v in per_module.items()},
+        "collectives_s": {k: v * 1e-9 for k, v in per_coll.items()},
+        "busy_by_device_s": {name: ns * 1e-9
+                             for name, ns in zip(sorted(devices), busy)},
         "device_ops": [[k, v * 1e-9] for k, v in
                        sorted(per_op.items(), key=lambda kv: -kv[1])[:TOP]],
         "idle_gaps": [[name, ns * 1e-9] for ns, name in gaps[:TOP]],
@@ -163,7 +198,8 @@ def empty(window_s: float) -> dict:
     """The reduction of a trace with no device plane (a rehearsal of
     the harness off the chip): nothing ran on a device."""
     return {"window_s": window_s, "busy_s": 0.0, "idle_share": None,
-            "executables_s": {}, "device_ops": [], "idle_gaps": [],
+            "executables_s": {}, "collectives_s": {},
+            "busy_by_device_s": {}, "device_ops": [], "idle_gaps": [],
             "n_devices": 0}
 
 
